@@ -14,6 +14,8 @@ import importlib
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 # section name -> (module, needs_dryrun_ledger, gate, description) —
 # `gate` sections return an exit code that fails the driver at the end
 # instead of aborting the remaining sections.
@@ -112,6 +114,7 @@ def main() -> None:
         sys.exit(f"unknown benchmark section(s) {unknown}; choose from "
                  f"{', '.join(sorted(SECTIONS))}")
 
+    enable_compile_cache()
     t0 = time.time()
     failures: list = []
     for name in SECTIONS:

@@ -63,7 +63,8 @@ import numpy as np
 
 from repro.core import energy as energy_mod
 from repro.core.opgraph import base_op
-from repro.kernels.conv2d import conv_geometry
+from repro.kernels.conv2d import (DEFAULT_CONV_ROWS, VMEM_BUDGET_BYTES,
+                                  conv_geometry, conv_vmem_bytes)
 from repro.kernels.epilogue import pad_channel_params
 from repro.kernels.int8_matmul import heuristic_blocks
 
@@ -75,7 +76,6 @@ CONV_ROWS = (1, 2, 4, 8, 16, 32, 64, 128)
 CONV_COUT_BLOCKS = (8, 16, 32, 64)
 HLS_UNROLLS = (1, 2, 4, 8, 16, 32, 64)
 HLS_MAX_UNROLL = 64           # DSP-lane budget of the flex dataflow analog
-DEFAULT_CONV_ROWS = 8         # the pre-autotune kernel default
 INT8_KINDS = ("int8_dense", "int8_conv")
 # LM kernel pools: flash-attention q/k block shapes and the SSD scan's
 # chunk length (DESIGN.md §15). 256 is the shipped kernel default.
@@ -360,9 +360,15 @@ def dense_candidates(m: int, k: int, n: int,
     return uniq
 
 
-def conv_candidates(h_out: int, cout: int,
+def conv_candidates(h: int, w: int, cin: int, kh: int, kw: int, cout: int,
+                    stride: int, padding: str,
                     fixed: Optional[KernelConfig] = None
                     ) -> List[KernelConfig]:
+    """The default blocking first, then every (rows, cout block) whose
+    VMEM working set fits the scoped budget — the pool offers only
+    blockings the chip's compiler accepts (the default is compiled for
+    every served shape by the chip compile tests)."""
+    h_out = conv_geometry(h, w, kh, kw, stride, padding, 1).h_out
     default = KernelConfig(rows_per_block=DEFAULT_CONV_ROWS)
     rows_cands = sorted({r for r in CONV_ROWS if r <= h_out} | {h_out})
     if fixed is not None:
@@ -372,8 +378,14 @@ def conv_candidates(h_out: int, cout: int,
     else:
         bcs = [0] + sorted(c for c in CONV_COUT_BLOCKS if c < _al8(cout))
         out = [default]
+
+    def fits(r: int, bc: int) -> bool:
+        g = conv_geometry(h, w, kh, kw, stride, padding, r)
+        return conv_vmem_bytes(g, kh, kw, cin,
+                               bc or _al8(cout)) <= VMEM_BUDGET_BYTES
+
     out += [KernelConfig(rows_per_block=r, cout_per_block=bc)
-            for r in rows_cands for bc in bcs]
+            for r in rows_cands for bc in bcs if fits(r, bc)]
     seen, uniq = set(), []
     for c in out:
         if c not in seen:
@@ -596,9 +608,7 @@ class Autotuner:
             m, k, n = sig
             return dense_candidates(m, k, n, fixed)
         if kind == "int8_conv":
-            _, h, w, cin, kh, kw, cout, stride, padding = sig
-            h_out = conv_geometry(h, w, kh, kw, stride, padding, 1).h_out
-            return conv_candidates(h_out, cout, fixed)
+            return conv_candidates(*sig[1:], fixed=fixed)
         if kind == "attention":
             return attention_candidates(sig[1], sig[2])
         if kind == "ssd":

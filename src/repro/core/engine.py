@@ -149,13 +149,26 @@ class Engine:
         self._compiled = {k: v for k, v in self._compiled.items()
                           if k[0] != "accel"}
 
-    def share_calibration(self, other: "Engine") -> None:
+    @property
+    def quantized(self) -> Optional[Dict[str, QuantizedLayer]]:
+        """The PTQ int8 weights per conv/dense node (None before
+        :meth:`calibrate`)."""
+        return self._quant
+
+    def share_calibration(self, other: "Engine", device=None) -> None:
         """Adopt ``other``'s PTQ calibration state (same graph topology
         and the same params): activation absmax, quantized weights, and
         the per-node PTQ error map. The twin-engine idiom the benchmarks
         and tests use to pay interpret-mode calibration once per model
-        instead of once per engine variant."""
+        instead of once per engine variant. ``device`` places the
+        adopted quantized weights there (e.g. a host-CPU twin of an
+        engine calibrated on the chip)."""
         self._quant = other._quant
+        if device is not None and self._quant is not None:
+            self._quant = {
+                k: QuantizedLayer(*jax.device_put((q.w_q, q.w_scale, q.bias),
+                                                  device))
+                for k, q in self._quant.items()}
         self._calib = other._calib
         self._ptq_err = other._ptq_err
         self._planned.pop("accel", None)

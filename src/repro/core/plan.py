@@ -46,7 +46,8 @@ from repro.core.opgraph import (RANDOM_OPS, Graph, Node, base_op,
                                 consumers, param_node)
 from repro.core.passes import PassContext, PassManager, PassReport
 from repro.kernels import ops as kops
-from repro.kernels.conv2d import conv_geometry, pad_input
+from repro.kernels.conv2d import (DEFAULT_CONV_ROWS, conv_geometry,
+                                  pad_input)
 
 
 # ---------------------------------------------------------------------------
@@ -839,9 +840,9 @@ def _run_quantized(qp: QuantNodePlan, x: jax.Array,
         h, w = int(x_q.shape[1]), int(x_q.shape[2])
         kh, kw = int(wq.shape[0]), int(wq.shape[1])
         rows = (config.rows_per_block
-                if config and config.rows_per_block else 8)
+                if config and config.rows_per_block else DEFAULT_CONV_ROWS)
         geom = conv_geometry(h, w, kh, kw, qp.stride, qp.padding, rows)
-        x_q = pad_input(x_q, geom)       # plan-time geometry, one pad op
+        x_q = pad_input(x_q, geom)       # plan-time geometry, one staging
         return kops.conv2d_int8(
             x_q, wq, packed.w_scale, packed.bias, x_scale=s,
             stride=qp.stride, padding=qp.padding, act=qp.act,

@@ -13,7 +13,7 @@ graph shapes are the traced avals minus the leading dim.
 
 The walk is a straightforward abstract interpretation of the
 ``ClosedJaxpr``: constvars/literals become ``ConstVal``s, call-like
-primitives (pjit, custom_jvp/vjp) are inlined, eqns whose inputs are all
+primitives (jit, custom_jvp/vjp) are inlined, eqns whose inputs are all
 constants are eagerly evaluated, and everything else dispatches through
 the translator registry (translators.py). Node specs are staged so
 peepholes can rewrite them (bias folding, sum-pool -> avgpool); the

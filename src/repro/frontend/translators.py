@@ -36,9 +36,8 @@ from repro.frontend.ir import ConstVal, NodeSpec, Ref, \
 TRANSLATORS: Dict[str, Callable] = {}
 
 # call-like primitives the walker inlines instead of translating
-INLINE_PRIMS = ("pjit", "custom_jvp_call", "custom_vjp_call",
-                "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
-                "closed_call", "core_call", "xla_call")
+INLINE_PRIMS = ("jit", "custom_jvp_call", "custom_vjp_call",
+                "closed_call", "call")
 
 # primitives whose translator must run even on all-constant inputs:
 # eagerly materializing a broadcast bakes in target dims and loses the

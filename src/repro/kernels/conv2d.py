@@ -1,18 +1,35 @@
-"""Pallas TPU kernel: NHWC conv2d as shift-and-matmul (the DPU conv engine).
+"""Pallas TPU kernel: NHWC int8 conv2d as shift-and-matmul (the DPU conv
+engine).
 
 TPU adaptation of the DPU's convolution array: instead of a systolic
-line-buffer (FPGA idiom), each grid step loads the KH input rows feeding
-one output row into VMEM and accumulates KH*KW shifted [W_out, Cin] x
-[Cin, Cout] matmuls on the MXU — im2col without ever materializing the
-patch matrix in HBM. Bias + ReLU fuse into the epilogue.
+line-buffer (FPGA idiom), each grid step loads the input row window that
+feeds ``rows`` output rows into VMEM and accumulates, per output row,
+KH*KW shifted [W_out, Cin] x [Cin, Cout] int8 matmuls in int32 on the
+MXU — im2col without ever materializing the patch matrix in HBM.
+Dequant, bias, activation and the optional int8 requant fuse into the
+epilogue; the grid blocks ``rows_per_block`` output rows per step so
+small feature maps don't drown in grid overhead.
 
-Space-use-case shapes (<=128x256 imgs, <=64 channels) keep the whole row
-set comfortably inside VMEM; the grid parallelizes over (batch, out-row).
-Supports stride 1/2 and 'SAME'/'VALID' padding (host-side pre-pad).
+Input staging (:func:`pad_input`, done once per batch and at plan time
+for the prepacked plans) applies the SAME/VALID pad and, for stride
+s > 1, splits the columns into s phases stored one after another along
+W: padded column ``j*s + p`` lands at ``p*W_pad/s + j``. Tap ``c`` of
+every output column then reads one contiguous run of phase ``c % s``,
+so the kernel never takes a strided slice of a vector (Mosaic lowers
+only unit-stride sublane slices). Rows stay in order; the row stride is
+a plain dynamic index on the untiled H axis.
+
+The input block is one row window of ``(rows - 1) * stride + KH`` rows,
+element-indexed over H so neighbouring windows share their halo rows
+without an HBM copy. The whole image never sits in VMEM: CNet's
+256x256x2 int8 input alone, padded to 128 lanes and double-buffered,
+exceeds the scoped VMEM limit. :func:`conv_vmem_bytes` estimates a
+configuration's working set for the autotuner's candidate filter.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -20,9 +37,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.epilogue import (apply_epilogue, normalize_act,
                                     out_dtype_for, pad_channel_params)
+
+DEFAULT_CONV_ROWS = 8           # output rows per grid step, untuned
+SCOPED_VMEM_BYTES = 16 * 2 ** 20       # TPU v5e default scoped VMEM limit
+# headroom for the compiler's own scratch: the autotuner offers no
+# blocking whose estimate exceeds this budget
+VMEM_BUDGET_BYTES = SCOPED_VMEM_BYTES * 3 // 4
 
 
 class ConvGeom(NamedTuple):
@@ -37,19 +59,22 @@ class ConvGeom(NamedTuple):
     pad_top: int
     pad_bottom: int                 # includes row-block coverage padding
     pad_left: int
-    pad_right: int
+    pad_right: int                  # includes rounding W_pad to the stride
     h_pad: int                      # padded input dims the kernel expects
     w_pad: int
+    stride: int
+    window: int                     # input rows one grid step reads
 
 
 @functools.lru_cache(maxsize=None)
 def conv_geometry(h: int, wd: int, kh: int, kw: int, stride: int,
                   padding: str, rows_per_block: int = 1) -> ConvGeom:
-    """Shared SAME/VALID geometry for the fp32 and int8 kernels: the
-    image is extended so every row window the grid touches — including
-    rows padded out to a whole number of ``rows_per_block`` blocks — is
-    in range. Zero padding is exact for both fp32 and int8 accumulation.
-    Pure function of static shapes, memoized."""
+    """SAME/VALID geometry shared by the kernel and the plan's input
+    staging: the image is extended so every row window the grid touches
+    — including rows padded out to a whole number of ``rows_per_block``
+    blocks — is in range, and W_pad is a whole number of stride phases.
+    Zero padding is exact for int8 accumulation. Pure function of static
+    shapes, memoized."""
     if padding == "SAME":
         h_out = -(-h // stride)
         w_out = -(-wd // stride)
@@ -69,133 +94,93 @@ def conv_geometry(h: int, wd: int, kh: int, kw: int, stride: int,
     need_w = (w_out - 1) * stride + kw
     bottom += max(need_h - (h + top + bottom), 0)
     right += max(need_w - (wd + left + right), 0)
+    right += -(wd + left + right) % stride
     return ConvGeom(h_out, w_out, rows, n_row_blocks, top, bottom, left,
-                    right, h + top + bottom, wd + left + right)
+                    right, h + top + bottom, wd + left + right, stride,
+                    (rows - 1) * stride + kh)
 
 
 def pad_input(x: jax.Array, g: ConvGeom) -> jax.Array:
     """Apply a plan-time :class:`ConvGeom` to one [B, H, W, C] batch —
-    the single input-staging pad both the kernels and the prepacked
-    plan path share."""
-    if (g.pad_top, g.pad_bottom, g.pad_left, g.pad_right) == (0, 0, 0, 0):
-        return x
-    return jnp.pad(x, ((0, 0), (g.pad_top, g.pad_bottom),
-                       (g.pad_left, g.pad_right), (0, 0)))
+    the single input-staging step (pad, then the stride-phase column
+    split) both the kernels and the prepacked plan path share."""
+    if (g.pad_top, g.pad_bottom, g.pad_left, g.pad_right) != (0, 0, 0, 0):
+        x = jnp.pad(x, ((0, 0), (g.pad_top, g.pad_bottom),
+                        (g.pad_left, g.pad_right), (0, 0)))
+    if g.stride > 1:
+        b, hp, wp, c = x.shape
+        x = x.reshape(b, hp, wp // g.stride, g.stride, c)
+        x = x.swapaxes(2, 3).reshape(b, hp, wp, c)
+    return x
 
 
-def _conv_geometry(x: jax.Array, kh: int, kw: int, stride: int,
-                   padding: str, rows_per_block: int = 1):
-    """Back-compat wrapper: ``(x_padded, h_out, w_out, rows,
-    n_row_blocks)`` over the cached :func:`conv_geometry`."""
-    g = conv_geometry(x.shape[1], x.shape[2], kh, kw, stride, padding,
-                      rows_per_block)
-    return pad_input(x, g), g.h_out, g.w_out, g.rows, g.n_row_blocks
+def block_bytes(shape: Tuple[int, ...], itemsize: int) -> int:
+    """Bytes one VMEM buffer of ``shape`` occupies in Mosaic's tiled
+    layout: the last dim padded to 128 lanes, the second-to-last to the
+    dtype's sublane tile (8 rows of 32-bit words, so 32 for int8)."""
+    shape = (1,) * max(2 - len(shape), 0) + tuple(shape)
+    sublanes = 8 * 4 // itemsize
+    lead = math.prod(shape[:-2])
+    return (lead * -(-shape[-2] // sublanes) * sublanes
+            * -(-shape[-1] // 128) * 128 * itemsize)
 
 
-def _kernel(x_ref, w_ref, b_ref, o_ref, *, kh: int, kw: int, w_out: int,
-            stride: int, relu: bool, has_bias: bool):
-    # x_ref block: [1, H_pad, W_pad, Cin] (whole image resident in VMEM —
-    # space-use-case feature maps are small); we slice the KH rows feeding
-    # this output row dynamically.
-    cout = o_ref.shape[-1]
-    row_start = pl.program_id(1) * stride
-    rows = x_ref[0, pl.dslice(row_start, kh)]            # [KH, W_pad, Cin]
-    acc = jnp.zeros((w_out, cout), jnp.float32)
+def conv_vmem_bytes(g: ConvGeom, kh: int, kw: int, cin: int, bc: int,
+                    out_itemsize: int = 4) -> int:
+    """Scoped-VMEM working set of one conv grid step: the double-buffered
+    int8 row window, weight slice, scale/bias and output tile, plus the
+    int32 row accumulator."""
+    blocks = (block_bytes((g.window, g.w_pad, cin), 1)
+              + block_bytes((kh, kw, cin, bc), 1)
+              + 2 * block_bytes((bc,), 4)
+              + block_bytes((g.rows, g.w_out, bc), out_itemsize))
+    return 2 * blocks + block_bytes((g.w_out, bc), 4)
+
+
+def _x_spec(g: ConvGeom, cin: int) -> pl.BlockSpec:
+    """The row-window input block of grid step (sample, row block, ...):
+    element offsets on every axis, overlapping windows on H."""
+    return pl.BlockSpec(
+        (pl.Element(1), pl.Element(g.window), pl.Element(g.w_pad),
+         pl.Element(cin)),
+        lambda bi, ri, *_: (bi, ri * g.rows * g.stride, 0, 0))
+
+
+def _row_acc(x_ref, w_ref, rr, *, w_out: int, stride: int):
+    """int32 accumulator of output row ``rr`` of the window: KH*KW
+    shifted [w_out, Cin] x [Cin, Cout] int8 matmuls over phase-major
+    input rows."""
+    kh, kw, _, cout = w_ref.shape
+    phase_w = x_ref.shape[2] // stride
+    acc = jnp.zeros((w_out, cout), jnp.int32)
     for r in range(kh):
-        row = rows[r].astype(jnp.float32)                # [W_pad, Cin]
+        row = x_ref[0, rr * stride + r]                  # [W_pad, Cin]
         for c in range(kw):
-            # static strided slice: w_out taps starting at column c
-            taps = jax.lax.slice(row, (c, 0),
-                                 (c + (w_out - 1) * stride + 1, row.shape[1]),
-                                 (stride, 1))            # [w_out, Cin]
+            start = (c % stride) * phase_w + c // stride
             acc += jax.lax.dot_general(
-                taps, w_ref[r, c].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-    if has_bias:
-        acc = acc + b_ref[...][None, :]
-    if relu:
-        acc = jnp.maximum(acc, 0.0)
-    o_ref[0, 0] = acc.astype(o_ref.dtype)
+                row[start:start + w_out], w_ref[r, c],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    return acc
 
 
-@functools.partial(jax.jit, static_argnames=("stride", "padding", "relu",
-                                             "interpret"))
-def conv2d(
-    x: jax.Array,                   # [B, H, W, Cin]
-    w: jax.Array,                   # [KH, KW, Cin, Cout]
-    bias: Optional[jax.Array] = None,
-    *,
-    stride: int = 1,
-    padding: str = "SAME",
-    relu: bool = False,
-    interpret: bool = True,
-) -> jax.Array:
-    b, h, wd, cin = x.shape
-    kh, kw, _, cout = w.shape
-    x, h_out, w_out, _, _ = _conv_geometry(x, kh, kw, stride, padding)
-    has_bias = bias is not None
-    if bias is None:
-        bias = jnp.zeros((cout,), jnp.float32)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, kh=kh, kw=kw, w_out=w_out, stride=stride,
-                          relu=relu, has_bias=has_bias),
-        grid=(b, h_out),
-        in_specs=[
-            pl.BlockSpec((1, x.shape[1], x.shape[2], cin),
-                         lambda bi, hi: (bi, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, cin, cout), lambda bi, hi: (0, 0, 0, 0)),
-            pl.BlockSpec((cout,), lambda bi, hi: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, w_out, cout),
-                               lambda bi, hi: (bi, hi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h_out, w_out, cout), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(x, w, bias)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# INT8 variant — the DPU conv engine proper: int8 taps x int8 weights
-# accumulated in int32 on the MXU, dequant + bias + ReLU fused into the
-# epilogue. Same shift-and-matmul structure (no im2col patch matrix ever
-# touches HBM); the grid blocks ``rows_per_block`` output rows per step so
-# small feature maps don't drown in grid overhead.
-# ---------------------------------------------------------------------------
-
-
-def _kernel_int8(x_ref, w_ref, ws_ref, b_ref, o_ref, *, kh: int, kw: int,
-                 w_out: int, stride: int, rows: int, x_scale: float,
-                 act, requant_scale, has_bias: bool):
-    # x_ref block: [1, H_pad, W_pad, Cin] int8 (whole image in VMEM);
-    # o_ref block: [1, rows, W_out, Cout] f32 — or int8 when the fused
-    # epilogue re-quantizes for the next layer (requant_scale set).
-    cout = o_ref.shape[-1]
-    cin = x_ref.shape[-1]
-    base = pl.program_id(1) * rows * stride
+def _kernel_int8(x_ref, w_ref, ws_ref, b_ref, o_ref, *, w_out: int,
+                 stride: int, x_scale: float, act, requant_scale,
+                 has_bias: bool):
+    # x_ref block: [1, window, W_pad, Cin] int8; o_ref block: [1, rows,
+    # W_out, Cout] f32 — or int8 when the fused epilogue re-quantizes for
+    # the next layer (requant_scale set).
     dequant = ws_ref[...] * jnp.float32(x_scale)         # [Cout]
-    for rr in range(rows):
-        row_start = base + rr * stride
-        taps_rows = x_ref[0, pl.dslice(row_start, kh)]   # [KH, W_pad, Cin] i8
-        acc = jnp.zeros((w_out, cout), jnp.int32)
-        for r in range(kh):
-            row = taps_rows[r]                           # [W_pad, Cin] int8
-            for c in range(kw):
-                taps = jax.lax.slice(
-                    row, (c, 0), (c + (w_out - 1) * stride + 1, cin),
-                    (stride, 1))                         # [w_out, Cin] int8
-                acc += jax.lax.dot_general(
-                    taps, w_ref[r, c],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
+
+    def row(rr, carry):
+        acc = _row_acc(x_ref, w_ref, rr, w_out=w_out, stride=stride)
         out = acc.astype(jnp.float32) * dequant[None, :]
         if has_bias:
             out = out + b_ref[...][None, :]
         out = apply_epilogue(out, act, requant_scale)
         o_ref[0, rr] = out.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, o_ref.shape[1], row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -214,12 +199,12 @@ def conv2d_int8(
     relu: bool = False,
     act: Optional[str] = None,      # 'relu' | 'sigmoid' epilogue
     requant_scale: Optional[float] = None,  # int8 output at this scale
-    rows_per_block: int = 8,
+    rows_per_block: int = DEFAULT_CONV_ROWS,
     cout_per_block: int = 0,        # 0 = no channel tiling (whole Cout)
     cout: Optional[int] = None,     # logical Cout when w arrives padded
     pre_padded: bool = False,       # x already staged per conv_geometry
     in_hw: Optional[Tuple[int, int]] = None,  # logical (H, W), pre_padded
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Quantized conv: ``deq(conv_int32(x_q, w_q))`` with fused epilogue.
 
@@ -275,49 +260,26 @@ def conv2d_int8(
         bias = jnp.zeros((cout_pad,), jnp.float32)
 
     kernel = functools.partial(
-        _kernel_int8, kh=kh, kw=kw, w_out=w_out, stride=stride, rows=rows,
-        x_scale=float(x_scale), act=act, requant_scale=requant_scale,
-        has_bias=has_bias)
-    out_sd = jax.ShapeDtypeStruct((b, h_out_pad, w_out, cout_pad),
-                                  out_dtype_for(requant_scale))
-    if bc == cout_pad:
-        out = pl.pallas_call(
-            kernel,
-            grid=(b, n_row_blocks),
-            in_specs=[
-                pl.BlockSpec((1, x_q.shape[1], x_q.shape[2], cin),
-                             lambda bi, ri: (bi, 0, 0, 0)),
-                pl.BlockSpec((kh, kw, cin, cout_pad),
-                             lambda bi, ri: (0, 0, 0, 0)),
-                pl.BlockSpec((cout_pad,), lambda bi, ri: (0,)),
-                pl.BlockSpec((cout_pad,), lambda bi, ri: (0,)),
-            ],
-            out_specs=pl.BlockSpec((1, rows, w_out, cout_pad),
-                                   lambda bi, ri: (bi, ri, 0, 0)),
-            out_shape=out_sd,
-            compiler_params=CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(x_q, w_q, w_scale, bias)
-    else:
-        out = pl.pallas_call(
-            kernel,
-            grid=(b, n_row_blocks, cout_pad // bc),
-            in_specs=[
-                pl.BlockSpec((1, x_q.shape[1], x_q.shape[2], cin),
-                             lambda bi, ri, ci: (bi, 0, 0, 0)),
-                pl.BlockSpec((kh, kw, cin, bc),
-                             lambda bi, ri, ci: (0, 0, 0, ci)),
-                pl.BlockSpec((bc,), lambda bi, ri, ci: (ci,)),
-                pl.BlockSpec((bc,), lambda bi, ri, ci: (ci,)),
-            ],
-            out_specs=pl.BlockSpec((1, rows, w_out, bc),
-                                   lambda bi, ri, ci: (bi, ri, 0, ci)),
-            out_shape=out_sd,
-            compiler_params=CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=interpret,
-        )(x_q, w_q, w_scale, bias)
+        _kernel_int8, w_out=w_out, stride=stride, x_scale=float(x_scale),
+        act=act, requant_scale=requant_scale, has_bias=has_bias)
+    out = pl.pallas_call(
+        kernel,
+        grid=(b, n_row_blocks, cout_pad // bc),
+        in_specs=[
+            _x_spec(g, cin),
+            pl.BlockSpec((kh, kw, cin, bc),
+                         lambda bi, ri, ci: (0, 0, 0, ci)),
+            pl.BlockSpec((bc,), lambda bi, ri, ci: (ci,)),
+            pl.BlockSpec((bc,), lambda bi, ri, ci: (ci,)),
+        ],
+        out_specs=pl.BlockSpec((1, rows, w_out, bc),
+                               lambda bi, ri, ci: (bi, ri, 0, ci)),
+        out_shape=jax.ShapeDtypeStruct((b, h_out_pad, w_out, cout_pad),
+                                       out_dtype_for(requant_scale)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(x_q, w_q, w_scale, bias)
     if h_out_pad != h_out or cout_pad != cout:
         out = out[:, :h_out, :, :cout]
     return out

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -2.0e38
 
@@ -89,7 +88,7 @@ def flash_attention(
     causal: bool = True,
     bq: int = 256,
     bk: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, sq, hq, hd = q.shape
     _, sk, hkv, _ = k.shape
@@ -130,7 +129,7 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.epilogue import (apply_epilogue, normalize_act,
                                     out_dtype_for)
 
@@ -95,7 +94,7 @@ def int8_matmul(
     out_dtype=jnp.float32,
     prepacked: bool = False,        # w/w_scale/bias arrive tile-aligned
     n_out: Optional[int] = None,    # logical N when prepacked
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     act = normalize_act(relu, act)
     out_dtype = out_dtype_for(requant_scale, out_dtype)
@@ -147,7 +146,7 @@ def int8_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, h: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q, x_scale, w_scale, bias)

@@ -1,12 +1,16 @@
 """Public jit'd kernel API.
 
-``interpret`` defaults to True on CPU (this container) and False when a
-real TPU backend is present, so the same call sites run emulated here and
-compiled Mosaic on hardware.
+Every call site goes through here, and here alone is decided how a
+Pallas kernel runs: compiled by Mosaic when the computation is placed on
+a TPU, in the Pallas interpreter when it is placed on the CPU (the
+tests, and the host-side reference a chip run compares against). Any
+other platform raises: a kernel never quietly runs emulated on a machine
+whose accelerator failed to come up. The platform is that of the default
+device (``jax.default_device``) when one is set, else of the default
+backend.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -19,13 +23,25 @@ from repro.kernels import quantize as _quant
 from repro.kernels import ssd as _ssd
 
 
-@functools.lru_cache(None)
+def platform() -> str:
+    """The platform the next traced computation is placed on."""
+    device = jax.config.jax_default_device
+    if device is None:
+        return jax.default_backend()
+    return device if isinstance(device, str) else device.platform
+
+
 def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+    return platform() == "tpu"
 
 
 def _interp() -> bool:
-    return not on_tpu()
+    p = platform()
+    if p not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on 'tpu' or interpreted on "
+            f"'cpu'; the computation is placed on {p!r}")
+    return p == "cpu"
 
 
 def int8_matmul(x_q, w_q, x_scale, w_scale, bias=None, *, relu=False,
@@ -36,11 +52,6 @@ def int8_matmul(x_q, w_q, x_scale, w_scale, bias=None, *, relu=False,
                                out_dtype=out_dtype, prepacked=prepacked,
                                n_out=n_out, interpret=_interp(),
                                **tiles)
-
-
-def conv2d(x, w, bias=None, *, stride=1, padding="SAME", relu=False):
-    return _conv2d.conv2d(x, w, bias, stride=stride, padding=padding,
-                          relu=relu, interpret=_interp())
 
 
 def conv2d_int8(x_q, w_q, w_scale, bias=None, *, x_scale=1.0, stride=1,

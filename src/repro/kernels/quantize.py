@@ -17,15 +17,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
-
-def _divisor_block(n: int, target: int) -> int:
-    """Largest divisor of n that is <= target."""
-    for b in range(min(target, n), 0, -1):
-        if n % b == 0:
-            return b
-    return n
+def _block(n: int, target: int) -> int:
+    """The whole dim when it fits in one block, else ``target``: Mosaic
+    takes a block dim that is either full or tile-aligned (the row target
+    a multiple of int8's 32-row tile, the column target of 128 lanes)."""
+    return n if n <= target else target
 
 
 def _kernel(x_ref, s_ref, q_ref):
@@ -42,27 +39,34 @@ def quantize_apply(
     *,
     bm: int = 256,
     bn: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
+    assert bm % 32 == 0 and bn % 128 == 0, (bm, bn)
     m, n = x.shape
-    bm, bn = _divisor_block(m, bm), _divisor_block(n, bn)
-    return pl.pallas_call(
+    bm, bn = _block(m, bm), _block(n, bn)
+    # a ragged dim pads up to whole blocks: zero rows/columns, unit scales
+    mp, np_ = -(-m // bm) * bm, -(-n // bn) * bn
+    if (mp, np_) != (m, n):
+        x = jnp.pad(x, ((0, mp - m), (0, np_ - n)))
+        scale = jnp.pad(scale, (0, np_ - n), constant_values=1.0)
+    q = pl.pallas_call(
         _kernel,
-        grid=(m // bm, n // bn),
+        grid=(mp // bm, np_ // bn),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bn,), lambda i, j: (j,)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int8),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, scale)
+    return q[:m, :n] if (mp, np_) != (m, n) else q
 
 
 def quantize(x: jax.Array, axis: Optional[int] = 0,
-             interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+             interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Symmetric per-channel (or per-tensor) INT8. Returns (q, scale)."""
     xf = x.astype(jnp.float32)
     if axis is None:

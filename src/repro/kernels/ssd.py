@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, init_ref,
             y_ref, final_ref, state_ref, *, n_chunks: int):
@@ -114,7 +112,7 @@ def ssd(x, B_, C_, dt, A, init_state=None, *, chunk: int = 256,
         ),
         out_shape=out_shapes,
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, B_, C_, dt, A.astype(jnp.float32), init_state)

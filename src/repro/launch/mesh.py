@@ -10,17 +10,25 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the sharding rules place
+    arrays with ``with_sharding_constraint``, which refuses the
+    ``Explicit`` axes ``jax.make_mesh`` gives by default."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pods: int = 0):
     """Small mesh for unit tests (requires enough local devices)."""
     if pods:
-        return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pods, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def tp_degree(mesh) -> int:

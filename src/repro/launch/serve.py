@@ -54,6 +54,7 @@ from repro.core.engine import Engine
 from repro.core.scheduler import (BACKENDS, ContinuousBatchingScheduler,
                                   capped_ladder, poisson_arrivals)
 from repro.core import inspector
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import SPACE_MODELS, synthetic_requests
 from repro.nn import model as model_lib
@@ -229,6 +230,12 @@ def serve_space(args) -> int:
     if args.checkpoint:
         faults_mod.save_checkpoint(args.checkpoint, sched.state_dict())
         print(f"[checkpoint] saved {args.checkpoint}")
+    missing = {name: t.n_submitted - t.n_completed
+               for name, t in sched.telemetry().items()
+               if t.n_completed != t.n_submitted}
+    if missing:
+        print(f"[serve] requests not completed: {missing}")
+        return 1
     return 0
 
 
@@ -468,6 +475,7 @@ def main(argv=None) -> int:
     ap.add_argument("--w8", action="store_true",
                     help="int8 PTQ weights (lm mode; §Perf B1)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.trace_demo:
         return trace_demo(args)
     if args.mode == "space":

@@ -24,7 +24,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.parallel.sharding import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -113,7 +112,7 @@ def _moe_routed_a2a(params: dict, x: jax.Array, cfg: ArchConfig, mesh
                        rules)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(x_spec, P(), w_spec, w_spec, wd_spec),
         out_specs=x_spec, check_vma=False)
     def routed(x_blk, router, w_gate, w_up, w_down):

@@ -24,7 +24,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from repro.parallel.sharding import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -92,7 +91,7 @@ def pipeline_forward(
         # so the out_spec (no stage axis) is well-defined on every shard
         return jax.lax.psum(outputs, stage_axis)
 
-    out = shard_map(
+    out = jax.shard_map(
         staged, mesh=mesh,
         in_specs=(p_specs, x_spec),
         out_specs=x_spec,

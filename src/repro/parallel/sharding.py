@@ -24,21 +24,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: new API takes ``check_vma``,
-    older ones (top-level or experimental) call the same knob
-    ``check_rep``."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_vma)
-    except TypeError:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-
 # ---------------------------------------------------------------------------
 # Rule tables
 # ---------------------------------------------------------------------------
@@ -238,8 +223,8 @@ def sp_gather_seq(x: jax.Array, batch_logical: str = "batch") -> jax.Array:
             return jax.lax.bitcast_convert_type(g, jnp.bfloat16)
         return jax.lax.all_gather(xb, "model", axis=1, tiled=True)
 
-    return shard_map(f, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
-                     check_vma=False)(x)
+    return jax.shard_map(f, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=out_spec, check_vma=False)(x)
 
 
 def tp_proj_scatter(inp: jax.Array, w: jax.Array, subscripts: str,
@@ -269,8 +254,8 @@ def tp_proj_scatter(inp: jax.Array, w: jax.Array, subscripts: str,
         return jax.lax.psum_scatter(y, "model", scatter_dimension=1,
                                     tiled=True)
 
-    return shard_map(f, mesh=mesh, in_specs=(in_spec, w_spec),
-                     out_specs=y_spec, check_vma=False)(inp, w)
+    return jax.shard_map(f, mesh=mesh, in_specs=(in_spec, w_spec),
+                         out_specs=y_spec, check_vma=False)(inp, w)
 
 
 def is_logical_leaf(x) -> bool:

@@ -101,9 +101,8 @@ def test_conv2d_int8_bit_exact_across_tile_configs(stride, padding):
     b = jnp.asarray(rng.uniform(-1, 1, cout), jnp.float32)
     ref = kops.conv2d_int8(x, w, ws, b, x_scale=0.5, stride=stride,
                            padding=padding, act="relu")
-    from repro.kernels.conv2d import conv_geometry
-    h_out = conv_geometry(h, wd, kk, kk, stride, padding, 1).h_out
-    for cfg in autotune_mod.conv_candidates(h_out, cout):
+    for cfg in autotune_mod.conv_candidates(h, wd, cin, kk, kk, cout,
+                                            stride, padding):
         out = kops.conv2d_int8(
             x, w, ws, b, x_scale=0.5, stride=stride, padding=padding,
             act="relu",

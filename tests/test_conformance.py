@@ -33,6 +33,9 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core.conformance import (ACCEL_ATOL, DECISION_OF, FLEX_TOL,
+                                    assert_flips_margin_bounded,
+                                    is_decision)
 from repro.core.engine import Engine
 from repro.core.opgraph import base_op
 from repro.models import SPACE_MODELS
@@ -46,42 +49,6 @@ TOP = RUNGS[-1]
 BACKENDS = ("cpu", "flex", "accel")
 N_CALIB = 4
 INPUT_KEY, PARAM_KEY, RNG_KEY = 123, 0, 7
-
-FLEX_TOL = dict(rtol=1e-5, atol=1e-5)
-# per-model PTQ |output - cpu| bounds (measured max on this fixture:
-# baseline 8.2e-3, cnet 8.5e-3, esperta 1.4e-1, logistic 0 [its dense is
-# PTQ-demoted to flex], reduced 2.9e-3, vae 2.6e-2) pinned with headroom
-ACCEL_ATOL = {
-    "baseline_net": 0.05,
-    "cnet_plus_scalar": 0.05,
-    "multi_esperta": 0.3,
-    "logistic_net": 1e-5,
-    "reduced_net": 0.02,
-    "vae_encoder": 0.1,
-}
-
-
-DECISION_OF = {"region": "head"}       # argmax output -> its logit tensor
-
-
-def _is_decision(name: str, key: str) -> bool:
-    return key.startswith("warn")
-
-
-def _assert_flips_margin_bounded(got, ref, logits_ref, atol, msg):
-    """Accel argmax flips are only legitimate on near-ties: every flipped
-    row's fp32 top-1/top-2 margin must be within what the pinned PTQ
-    logit perturbation can overcome, and flips must stay rare."""
-    flipped = np.nonzero(got != ref)[0]
-    assert flipped.size <= max(1, int(0.15 * got.size)), (
-        f"{msg}: {flipped.size}/{got.size} PTQ decision flips")
-    for i in flipped:
-        top = np.sort(logits_ref[i].ravel())
-        margin = float(top[-1] - top[-2])
-        assert margin <= 2 * atol, (
-            f"{msg}: row {i} flipped despite decisive fp32 margin "
-            f"{margin:.3e} > 2*atol={2*atol:.3e}")
-
 
 _STATE = {}
 
@@ -129,12 +96,12 @@ def test_backend_matches_cpu_reference(name, backend, rung):
         assert a.shape == r.shape, msg
         if np.issubdtype(a.dtype, np.integer):
             if backend == "accel" and k in DECISION_OF:
-                _assert_flips_margin_bounded(
+                assert_flips_margin_bounded(
                     a, r, ref[DECISION_OF[k]][:rung], ACCEL_ATOL[name], msg)
             else:
                 np.testing.assert_array_equal(a, r, err_msg=msg)
         elif backend == "accel":
-            if _is_decision(name, k):
+            if is_decision(k):
                 continue                 # pinned by rung-invariance below
             np.testing.assert_allclose(a, r, rtol=1e-5,
                                        atol=ACCEL_ATOL[name], err_msg=msg)

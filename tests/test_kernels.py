@@ -62,17 +62,62 @@ def test_int8_matmul_int32_exactness():
     (9, 9, 2, 4, 3, 2, "VALID"),
 ])
 def test_conv2d_sweep(h, w, cin, cout, kh, stride, padding):
+    """The int8 conv kernel against the f32 oracle run on the dequantized
+    operands (x_q * x_scale, w_q * w_scale)."""
     rng = np.random.default_rng(h * 31 + w)
-    x = jnp.asarray(rng.standard_normal((2, h, w, cin)), jnp.float32)
-    wgt = jnp.asarray(rng.standard_normal((kh, kh, cin, cout)) * 0.1,
-                      jnp.float32)
+    x_q = jnp.asarray(rng.integers(-127, 128, (2, h, w, cin)), jnp.int8)
+    w_q = jnp.asarray(rng.integers(-127, 128, (kh, kh, cin, cout)), jnp.int8)
+    xs = 0.02
+    ws = jnp.asarray(rng.random(cout) * 1e-3 + 1e-4, jnp.float32)
     b = jnp.asarray(rng.standard_normal(cout) * 0.1, jnp.float32)
-    got = kops.conv2d(x, wgt, b, stride=stride, padding=padding, relu=True)
-    want = ref.conv2d_ref(x, wgt, b, stride=stride, padding=padding,
-                          relu=True)
+    got = kops.conv2d_int8(x_q, w_q, ws, b, x_scale=xs, stride=stride,
+                           padding=padding, relu=True)
+    want = ref.conv2d_ref(x_q.astype(jnp.float32) * xs,
+                          w_q.astype(jnp.float32) * ws, b, stride=stride,
+                          padding=padding, relu=True)
     assert got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+# the accel convs the six nets serve: (H, W, Cin, Cout, stride), SAME
+SERVED_CONVS = [
+    (256, 256, 2, 48, 1), (128, 128, 48, 48, 1), (64, 64, 48, 32, 1),  # CNet
+    (128, 256, 3, 8, 2), (64, 128, 8, 32, 2), (32, 64, 32, 96, 2),     # VAE
+    (16, 32, 96, 144, 2), (8, 16, 144, 144, 2),
+]
+
+
+@pytest.mark.parametrize("epilogue", ["int32", "requant"])
+@pytest.mark.parametrize("h,w,cin,cout,stride", SERVED_CONVS)
+def test_conv2d_int8_bit_exact_at_served_shapes(h, w, cin, cout, stride,
+                                                epilogue):
+    """The row-window / stride-phase kernel reproduces the lax conv bit
+    for bit at every served shape (the shapes the chip compile tests
+    compile): the int32 accumulator itself (unit scales, no bias), and
+    the full relu + int8 requant epilogue."""
+    rng = np.random.default_rng(h + w + cin + cout)
+    x_q = jnp.asarray(rng.integers(-127, 128, (1, h, w, cin)), jnp.int8)
+    w_q = jnp.asarray(rng.integers(-127, 128, (3, 3, cin, cout)), jnp.int8)
+    acc = jax.lax.conv_general_dilated(
+        x_q.astype(jnp.int32), w_q.astype(jnp.int32), (stride, stride),
+        "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    if epilogue == "int32":
+        got = kops.conv2d_int8(x_q, w_q, jnp.ones((cout,), jnp.float32),
+                               stride=stride)
+        want = acc.astype(jnp.float32)
+    else:
+        ws = jnp.asarray(rng.random(cout) * 1e-3 + 1e-4, jnp.float32)
+        bias = jnp.asarray(rng.standard_normal(cout), jnp.float32)
+        xs, rq = 0.017, 0.05
+        got = kops.conv2d_int8(x_q, w_q, ws, bias, x_scale=xs,
+                               stride=stride, act="relu", requant_scale=rq)
+        want = jnp.maximum(
+            acc.astype(jnp.float32) * (ws * jnp.float32(xs)) + bias, 0.0)
+        want = jnp.clip(jnp.round(want / jnp.float32(rq)), -127,
+                        127).astype(jnp.int8)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -139,3 +184,18 @@ def test_quantize_sweep(shape, axis, dtype):
                             else np.expand_dims(np.asarray(s), axis))
     err = np.abs(np.asarray(x, np.float32) - np.asarray(deq))
     assert (err <= scale_full * 0.51 + 1e-6).all()
+
+
+@pytest.mark.parametrize("k,n", [(32769, 92), (300, 43), (40, 300)],
+                         ids=["cnet_fc1", "ragged_rows", "ragged_cols"])
+def test_quantize_bit_exact_when_blocks_pad(k, n):
+    """Dims past one block pad to whole tile-aligned blocks (CNet fc1's
+    K = 32769 has no aligned divisor); the codes are exactly the
+    kernel's formula, pad rows/columns sliced off."""
+    rng = np.random.default_rng(k + n)
+    x = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
+    q, s = kops.quantize(x, axis=0)
+    want = jnp.clip(jnp.round(x * (1.0 / s)[None, :]), -127,
+                    127).astype(jnp.int8)
+    assert q.shape == (k, n)
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(want))

@@ -47,7 +47,8 @@ def test_a2a_moe_matches_scatter_multidevice():
         cfg_s = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, ep_impl="scatter"))
         dims = compute_dims(cfg, tp=4)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         params = build_params(moe_mod.moe_spec(cfg, dims), jax.random.PRNGKey(0))
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                               jnp.float32)
@@ -77,7 +78,8 @@ def test_meshed_forward_matches_unmeshed_multidevice():
                                       cfg.vocab_size)
             ref = model_lib.forward(params, toks, cfg, dims, mode="train",
                                     remat=False)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = jax.make_mesh((2, 4), ("data", "model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             with use_mesh(mesh):
                 got = jax.jit(lambda p, t: model_lib.forward(
                     p, t, cfg, dims, mode="train", remat=False))(params, toks)

@@ -40,7 +40,8 @@ def test_pipeline_forward_matches_sequential():
             return h
         ref = jax.vmap(seq)(x)
 
-        mesh = jax.make_mesh((2, 4), ("data", "stage"))
+        mesh = jax.make_mesh((2, 4), ("data", "stage"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         got = jax.jit(lambda p, x: pipeline_forward(
             p, x, block_fn, mesh, extra_specs=P("data", None, None)))(params, x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

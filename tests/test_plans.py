@@ -5,7 +5,7 @@
 * plan-cache behavior: compiling twice returns the same executable and
   does not re-trace; a new batch size traces exactly once more; calling
   a compiled plan never traces.
-* Pallas conv2d (fp32 + int8) vs lax.conv_general_dilated across
+* the Pallas int8 conv2d vs lax.conv_general_dilated across
   stride/padding combos.
 * the PTQ fidelity gate demotes below-noise-floor layers to flex.
 """
@@ -167,14 +167,20 @@ def test_partition_segments_groups_runs():
     assert segs[1].nodes == ("sample",)
 
 
-def test_ptq_gate_demotes_noise_floor_layers(engines):
-    _, e = engines["logistic_net"]
-    plan = e.planned("accel")
-    # the 8192-in/4-out head's output sits below int8 activation noise;
-    # the gate must route it to flex and the accel run must then match
-    # flex exactly on that node
-    assert "head" in plan.demoted
+def test_ptq_gate_demotes_noise_floor_layers():
+    # one hot voxel (a particle hit) in the calibration set sets the
+    # per-tensor activation scale ~1000x above the rest of the data, so
+    # on the other sample every head input rounds to zero and the head's
+    # int8 output is pure noise: the gate must route it to flex, and the
+    # accel run must then match flex exactly on that node
     m = SPACE_MODELS["logistic_net"]
+    e = Engine(m.build_graph(), m.init_params(jax.random.PRNGKey(0)))
+    hot = np.array(m.synthetic_input(jax.random.PRNGKey(0))["dist"])
+    hot[(0,) * hot.ndim] = 1e3 * np.abs(hot).max()
+    e.calibrate([m.synthetic_input(jax.random.PRNGKey(1)), {"dist": hot}])
+    plan = e.planned("accel")
+    assert e._ptq_err["head"] > e.ptq_demote_threshold
+    assert "head" in plan.demoted
     x = m.synthetic_input(jax.random.PRNGKey(3))
     a = e.run(x, "flex")
     b = e.run(x, "accel")
@@ -191,16 +197,25 @@ def test_ptq_gate_demotes_noise_floor_layers(engines):
     (1, "SAME"), (2, "SAME"), (1, "VALID"), (2, "VALID"),
 ])
 def test_pallas_conv2d_matches_lax(stride, padding):
+    """The int8 conv with a fused int8 requant epilogue, channel-tiled
+    and row-blocked, reproduces the int32-exact lax conv bit for bit."""
     rng = np.random.default_rng(stride * 7 + len(padding))
-    x = jnp.asarray(rng.standard_normal((2, 14, 18, 3)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((3, 3, 3, 8)) * 0.2, jnp.float32)
+    x_q = jnp.asarray(rng.integers(-127, 128, (2, 14, 18, 3)), jnp.int8)
+    w_q = jnp.asarray(rng.integers(-127, 128, (3, 3, 3, 8)), jnp.int8)
+    ws = jnp.asarray(rng.random(8) * 1e-3 + 1e-4, jnp.float32)
     b = jnp.asarray(rng.standard_normal(8) * 0.1, jnp.float32)
-    got = kops.conv2d(x, w, b, stride=stride, padding=padding)
-    want = jax.lax.conv_general_dilated(
-        x, w, (stride, stride), padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
+    xs, rq = 0.031, 0.02
+    got = kops.conv2d_int8(x_q, w_q, ws, b, x_scale=xs, stride=stride,
+                           padding=padding, requant_scale=rq,
+                           rows_per_block=3, cout_per_block=4)
+    acc = jax.lax.conv_general_dilated(
+        x_q.astype(jnp.int32), w_q.astype(jnp.int32), (stride, stride),
+        padding, dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    want = acc.astype(jnp.float32) * (ws * jnp.float32(xs)) + b
+    want = jnp.clip(jnp.round(want / jnp.float32(rq)), -127,
+                    127).astype(jnp.int8)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("stride,padding", [
