@@ -1,0 +1,12 @@
+"""The benchmark's own tests run on the CPU:
+
+    JAX_PLATFORMS=cpu python -m pytest bench/tests
+"""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+for path in (ROOT, os.path.join(ROOT, "src")):
+    if path not in sys.path:
+        sys.path.insert(0, path)
