@@ -40,6 +40,7 @@ import jax
 import numpy as np
 
 from repro.core import memory as memory_mod
+from repro.core import spans
 
 
 @dataclasses.dataclass
@@ -211,7 +212,8 @@ class DispatchTicket:
                 "retire() after a failed retirement: this ticket's batch "
                 "was already abandoned (its outputs are gone)")
         try:
-            host_out = self.pipeline._unstage(self.outputs, self.n_real)
+            with spans.span("serve.retire.fetch"):
+                host_out = self.pipeline._unstage(self.outputs, self.n_real)
             t1 = time.perf_counter()
             keep = self.pipeline._keep(host_out, self.n_real)
             t2 = time.perf_counter()
@@ -279,16 +281,20 @@ class ServingPipeline:
         slot = self.arena.acquire()
         if slot is None:
             self.arena.n_fallback += 1
-            return stage_batch(reqs, self.batch_size), None
-        host = self.arena.stage(slot, reqs)
-        return jax.device_put(host), slot
+            with spans.span("serve.stage"):
+                return stage_batch(reqs, self.batch_size), None
+        with spans.span("serve.stage"):
+            host = self.arena.stage(slot, reqs)
+        with spans.span("serve.transfer"):
+            return jax.device_put(host), slot
 
     def _dispatch(self, staged: Dict[str, jax.Array], rng: jax.Array
                   ) -> Tuple[Dict[str, jax.Array], jax.Array]:
         """One plan call — async dispatch, nothing forced; returns
         (unforced device outputs, carried-over rng)."""
-        rngs = jax.random.split(rng, self.batch_size + 1)
-        return self._plan(staged, rngs[1:]), rngs[0]
+        with spans.span("serve.launch"):
+            rngs = jax.random.split(rng, self.batch_size + 1)
+            return self._plan(staged, rngs[1:]), rngs[0]
 
     def _issue(self, staged: Dict[str, jax.Array], slot: Optional[int],
                n_real: int, stage_time: float, rng: jax.Array

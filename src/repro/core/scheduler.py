@@ -74,6 +74,7 @@ import numpy as np
 
 from repro.core.energy import (CostSignature, Draw, PipelineTimeline,
                                PowerEnvelope, StageCost)
+from repro.core import spans
 from repro.core.pipeline import (BatchResult, DispatchTicket,
                                  ServingPipeline)
 
@@ -557,10 +558,11 @@ class ContinuousBatchingScheduler:
                arrival: Optional[float] = None) -> int:
         """Enqueue one request; returns its id. ``arrival`` defaults to the
         wall clock (async mode); trace mode passes virtual timestamps."""
-        with self._lock:
+        with spans.span("serve.submit"), self._lock:
             svc = self._svcs[model]
             arrival = time.monotonic() if arrival is None else float(arrival)
             rid = self._next_rid
+            spans.set_id(rid)
             self._next_rid += 1
             svc.queue.append(Request(rid, model, inputs, arrival,
                                      arrival + svc.deadline_s))
@@ -607,6 +609,10 @@ class ContinuousBatchingScheduler:
         regardless of deadlines (used by drain) but still respects the
         envelope. Returns the dispatch record, or None if every queue is
         waiting or deferred."""
+        with spans.span("serve.step"):
+            return self._step(now, force)
+
+    def _step(self, now: float, force: bool) -> Optional[DispatchRecord]:
         with self._lock:
             n = len(self._order)
             for k in range(n):
@@ -644,6 +650,7 @@ class ContinuousBatchingScheduler:
                 break
             else:
                 return None
+            spans.set_id(reqs[0].rid)   # a batch is named by its head
             rng = svc.next_rng()
             sig = svc.costs[(backend, rung)]
 
@@ -747,39 +754,41 @@ class ContinuousBatchingScheduler:
         the staging slot), observe the EWMA service time from ticket
         retirement, and emit its completions (FIFO retirement keeps
         completion order identical to the synchronous path)."""
-        try:
-            result = inf.ticket.retire()
-        except BaseException:
-            # no silent loss on an async failure either: batch back at
-            # the queue head in original order, with the ORIGINAL arrival
-            # timestamps and deadlines (Request objects are frozen), and
-            # the draw refunded. The dispatch record is marked failed so
-            # the inevitable re-dispatch cannot double-count the batch in
-            # p50/p99, fill-histogram, or energy telemetry.
+        with spans.span("serve.retire", inf.reqs[0].rid):
+            try:
+                result = inf.ticket.retire()
+            except BaseException:
+                # no silent loss on an async failure either: batch back at
+                # the queue head in original order, with the ORIGINAL arrival
+                # timestamps and deadlines (Request objects are frozen), and
+                # the draw refunded. The dispatch record is marked failed so
+                # the inevitable re-dispatch cannot double-count the batch in
+                # p50/p99, fill-histogram, or energy telemetry.
+                with self._lock:
+                    inf.svc.queue.extendleft(reversed(inf.reqs))
+                    if inf.draw is not None:
+                        self.envelope.remove(inf.draw)
+                    self.dispatches[inf.rec_idx] = dataclasses.replace(
+                        self.dispatches[inf.rec_idx], failed=True)
+                raise
+            measured = time.perf_counter() - inf.t0
+            service = (inf.sig.latency_s if self.clock == "modeled"
+                       else measured)
             with self._lock:
-                inf.svc.queue.extendleft(reversed(inf.reqs))
-                if inf.draw is not None:
-                    self.envelope.remove(inf.draw)
-                self.dispatches[inf.rec_idx] = dataclasses.replace(
-                    self.dispatches[inf.rec_idx], failed=True)
-            raise
-        measured = time.perf_counter() - inf.t0
-        service = inf.sig.latency_s if self.clock == "modeled" else measured
-        with self._lock:
-            inf.svc.observe_service(inf.backend, inf.rung, service)
-            if self.clock != "modeled":
-                # telemetry should report the true dispatch->retirement
-                # service; the virtual clock already advanced by the
-                # non-blocking dispatch time at dispatch
-                self.dispatches[inf.rec_idx] = dataclasses.replace(
-                    self.dispatches[inf.rec_idx], service_time=service)
-            finished = inf.started + service
-            for i, req in enumerate(inf.reqs):
-                self.completions.append(Completion(
-                    req.rid, req.model,
-                    {k: v[i] for k, v in result.outputs.items()},
-                    result.keep[i], req.arrival, finished, inf.rung,
-                    inf.n_real, req.deadline))
+                inf.svc.observe_service(inf.backend, inf.rung, service)
+                if self.clock != "modeled":
+                    # telemetry should report the true dispatch->retirement
+                    # service; the virtual clock already advanced by the
+                    # non-blocking dispatch time at dispatch
+                    self.dispatches[inf.rec_idx] = dataclasses.replace(
+                        self.dispatches[inf.rec_idx], service_time=service)
+                finished = inf.started + service
+                for i, req in enumerate(inf.reqs):
+                    self.completions.append(Completion(
+                        req.rid, req.model,
+                        {k: v[i] for k, v in result.outputs.items()},
+                        result.keep[i], req.arrival, finished, inf.rung,
+                        inf.n_real, req.deadline))
 
     def _drain_inflight(self, keep: int = 0) -> None:
         """Retire oldest-first until at most ``keep`` remain in flight."""
@@ -1055,7 +1064,8 @@ class ContinuousBatchingScheduler:
                     self._thread_error = ex
                     return
                 if rec is None:
-                    time.sleep(poll_s)
+                    with spans.span("serve.poll"):
+                        time.sleep(poll_s)
 
         self._thread = threading.Thread(target=loop, daemon=True,
                                         name="cb-scheduler")
@@ -1181,6 +1191,14 @@ class ContinuousBatchingScheduler:
                 f"({ov['serial_span_s']:.4f} s serial -> "
                 f"{ov['pipelined_span_s']:.4f} s pipelined over "
                 f"{ov['n_dispatches']} dispatches)  occupancy[{occ}]")
+        if spans.enabled():
+            for name, st in spans.summary().items():
+                lines.append(
+                    f"[span] {name} n={st['count']}  "
+                    f"total={st['total_s']*1e3:.2f} ms  "
+                    f"p50={st['p50_s']*1e3:.3f} ms  "
+                    f"p95={st['p95_s']*1e3:.3f} ms  "
+                    f"max={st['max_s']*1e3:.3f} ms")
         return "\n".join(lines)
 
 

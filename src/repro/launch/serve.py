@@ -53,7 +53,7 @@ from repro.core.energy import PowerEnvelope
 from repro.core.engine import Engine
 from repro.core.scheduler import (BACKENDS, ContinuousBatchingScheduler,
                                   capped_ladder, poisson_arrivals)
-from repro.core import inspector
+from repro.core import inspector, spans
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import SPACE_MODELS, synthetic_requests
@@ -391,6 +391,10 @@ def main(argv=None) -> int:
     ap.add_argument("--staging-buffers", type=int, default=2,
                     help="host staging slots per (model, rung) = max "
                          "in-flight dispatches (2 = double buffering)")
+    ap.add_argument("--spans", action="store_true",
+                    help="record the served path's program spans "
+                         "(DESIGN.md §12) and print each one's count, "
+                         "total, p50, p95 and max after the summary")
     ap.add_argument("--no-fuse", action="store_true",
                     help="skip the graph-compiler pass pipeline "
                          "(DESIGN.md §10) and serve the op-by-op plans")
@@ -476,6 +480,8 @@ def main(argv=None) -> int:
                     help="int8 PTQ weights (lm mode; §Perf B1)")
     args = ap.parse_args(argv)
     enable_compile_cache()
+    if args.spans:
+        spans.enable()
     if args.trace_demo:
         return trace_demo(args)
     if args.mode == "space":
