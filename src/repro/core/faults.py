@@ -189,8 +189,15 @@ class SEUInjector:
     def flip_staging(self, arena, slot: int = 0) -> Tuple[str, int, int]:
         """Flip one bit in a host staging buffer (transient corruption:
         ``stage()`` rewrites every row of every buffer, so the flip only
-        matters if it lands between staging and dispatch)."""
+        matters if it lands between staging and dispatch). Only
+        host-staged inputs have buffers: rows handed to the runtime
+        directly are never copied into a slot."""
         bufs = arena._bufs[slot]
+        if not bufs:
+            raise ValueError(
+                f"staging slot {slot} holds no host buffer: every input "
+                f"of {arena.staging.graph_name!r} goes to the runtime "
+                f"directly")
         name = sorted(bufs)[int(self._rng.integers(len(bufs)))]
         flat = bufs[name].view(np.uint8).reshape(-1)
         byte = int(self._rng.integers(flat.size))

@@ -90,26 +90,42 @@ class ArenaPlan:
         return "\n".join(lines)
 
 
+# A graph input whose fp32 row is larger than this is not copied on the
+# host: its rows are passed as they are to a jitted stack, whose call
+# hands each one to the runtime. On a TPU v5e host that costs ~0.12 ms a
+# row (3.9-4.1 ms for 32 rows of 384 or 512 KiB, whatever their size),
+# while the Python row copy into a slot ran at ~1 us/KiB inside the
+# served loop (8.6 ms for a 12 MiB VAE batch, 13.9 ms for a 16 MiB CNet
+# one), so the two cross near 100 KiB. No served net has a row between
+# the MMS nets' 64 KiB (staged) and the VAE tile's 384 KiB (direct).
+DIRECT_ROW_BYTES = 64 * 1024
+
+
 @dataclasses.dataclass(frozen=True)
 class StagingPlan:
     """The HOST-side staging arena for one (plan, batch rung): the fixed
-    fp32 batch-buffer shape of every graph input and the slot count the
-    double-buffered pipeline preallocates (DESIGN.md §12).
+    fp32 batch-buffer shape of every host-staged graph input, the
+    per-row shape of every input handed to the runtime directly, and the
+    slot count the double-buffered pipeline preallocates (DESIGN.md §12).
 
     Planned statically, like the device arena above: the serving loop
     reuses these buffers for every dispatch (batch k+1 is assembled in a
     free slot while batch k computes) instead of allocating a fresh host
     stack per `jax.device_put`. A slot is owned by its in-flight dispatch
     until the dispatch's ticket retires — `jax.device_put` may alias host
-    memory, so an owned slot is never rewritten."""
+    memory, so an owned slot is never rewritten. Inputs whose rows are
+    larger than ``DIRECT_ROW_BYTES`` get no slot buffer: their rows go to
+    the runtime as submitted and are stacked on the device."""
     graph_name: str
     batch_size: int
     slots: int
     input_shapes: Dict[str, Tuple[int, ...]]    # name -> [B, ...] shape
+    direct_shapes: Dict[str, Tuple[int, ...]] = dataclasses.field(
+        default_factory=dict)                   # name -> per-row shape
 
     @property
     def input_bytes(self) -> Dict[str, int]:
-        """fp32 bytes of each input buffer, per slot."""
+        """fp32 bytes of each host-staged input buffer, per slot."""
         return {k: int(np.prod(s, dtype=np.int64)) * 4
                 for k, s in self.input_shapes.items()}
 
@@ -122,25 +138,35 @@ class StagingPlan:
         return self.slot_bytes * self.slots
 
     def summary(self) -> str:
+        direct = (f", direct rows: {', '.join(sorted(self.direct_shapes))}"
+                  if self.direct_shapes else "")
         return (f"staging[{self.graph_name}/b{self.batch_size}]: "
                 f"{self.slots} slot(s) x {self.slot_bytes:,} B "
-                f"({self.total_bytes:,} B host arena)")
+                f"({self.total_bytes:,} B host arena{direct})")
 
 
 def plan_staging(graph: Graph, batch_size: int, slots: int = 2
                  ) -> StagingPlan:
     """Size the host staging arena for ``batch_size`` dispatches of
-    ``graph``: one fp32 ``[batch_size, ...]`` buffer per graph input per
-    slot. ``slots=2`` is classic double buffering; more slots deepen the
-    in-flight window the async scheduler may keep open."""
+    ``graph``: one fp32 ``[batch_size, ...]`` buffer per host-staged
+    graph input per slot. ``slots=2`` is classic double buffering; more
+    slots deepen the in-flight window the async scheduler may keep open.
+    An input whose fp32 row is larger than ``DIRECT_ROW_BYTES`` is listed
+    under ``direct_shapes`` instead and gets no buffer."""
     if batch_size < 1:
         raise ValueError(f"batch_size must be >= 1, got {batch_size}")
     if slots < 1:
         raise ValueError(f"staging needs >= 1 slot, got {slots}")
-    shapes = {name: (batch_size,) + tuple(shape)
-              for name, shape in graph.graph_inputs.items()}
+    staged, direct = {}, {}
+    for name, shape in graph.graph_inputs.items():
+        row = tuple(shape)
+        if int(np.prod(row, dtype=np.int64)) * 4 > DIRECT_ROW_BYTES:
+            direct[name] = row
+        else:
+            staged[name] = (batch_size,) + row
     return StagingPlan(graph_name=graph.name, batch_size=batch_size,
-                       slots=slots, input_shapes=shapes)
+                       slots=slots, input_shapes=staged,
+                       direct_shapes=direct)
 
 
 def _nbytes(graph: Graph, name: str,

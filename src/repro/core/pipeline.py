@@ -37,6 +37,7 @@ from typing import (Callable, Deque, Dict, Iterable, List, Optional,
                     Tuple)
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import memory as memory_mod
@@ -115,8 +116,10 @@ def stage_batch(reqs: List[Dict[str, np.ndarray]], batch_size: int
 class HostStagingArena:
     """The pool of reusable host batch buffers a :class:`StagingPlan`
     sizes: ``slots`` preallocated fp32 ``[B, ...]`` NumPy buffers per
-    graph input, filled in place per dispatch instead of re-allocating a
-    fresh stack for every ``jax.device_put``.
+    host-staged graph input, filled in place per dispatch instead of
+    re-allocating a fresh stack for every ``jax.device_put``. Inputs the
+    plan hands to the runtime directly have no buffer here, but a
+    dispatch still owns a slot: the pool bounds the dispatches in flight.
 
     Donation invariant (DESIGN.md §12): ``acquire()`` transfers slot
     ownership to the dispatch being staged; the slot returns to the free
@@ -154,9 +157,10 @@ class HostStagingArena:
 
     def stage(self, slot: int, reqs: List[Dict[str, np.ndarray]]
               ) -> Dict[str, np.ndarray]:
-        """Fill ``slot`` in place with ``reqs`` (+ repeat-last padding);
-        returns the slot's buffer dict. Bit-identical to `stage_batch`:
-        the same fp32 casts, the same padding rule."""
+        """Fill ``slot``'s host-staged buffers in place with ``reqs``
+        (+ repeat-last padding); returns the slot's buffer dict.
+        Bit-identical to `stage_batch`: the same fp32 casts, the same
+        padding rule."""
         n = len(reqs)
         bufs = self._bufs[slot]
         for k, buf in bufs.items():
@@ -182,13 +186,18 @@ class DispatchTicket:
     (a pool slot must never leak with its dispatch — the old leak
     silently drained the pool into the ``n_fallback`` path forever); the
     ticket is left poisoned, so a later ``retire()`` raises RuntimeError
-    instead of fabricating a result."""
+    instead of fabricating a result.
+
+    ``rows`` holds the host rows handed to the runtime directly (not
+    copied into the slot) until retirement: a submitted input must not
+    be freed or written while its batch is in flight."""
     pipeline: "ServingPipeline"
     outputs: Optional[Dict[str, jax.Array]]
     n_real: int
     slot: Optional[int]
     stage_time: float
     dispatched_at: float                # perf_counter at dispatch
+    rows: Tuple[np.ndarray, ...] = ()
     _result: Optional[BatchResult] = None
 
     @property
@@ -196,6 +205,7 @@ class DispatchTicket:
         return self._result is not None
 
     def _release(self) -> None:
+        self.rows = ()
         if self.slot is not None:
             self.pipeline.arena.release(self.slot)
             self.slot = None
@@ -238,6 +248,12 @@ class ServingPipeline:
     (and the padding sliced off), so a request stream of any length hits
     exactly one executable. ``staging_buffers`` sizes the host staging
     arena (2 = classic double buffering).
+
+    Inputs with large rows (``memory.DIRECT_ROW_BYTES``) skip the arena:
+    each request's row is passed as it is to a jitted ``assemble``, whose
+    call hands it to the runtime and stacks the batch on the device.
+    ``n_rows_direct`` / ``n_rows_staged`` count (input, real row) pairs
+    on each path.
     """
 
     def __init__(self, engine, backend: str = "flex",
@@ -253,6 +269,10 @@ class ServingPipeline:
             self._plan.plan.graph, batch_size, staging_buffers)
         self.arena = HostStagingArena(self.staging)
         self._inflight: Deque[DispatchTicket] = deque()
+        self._assemble = jax.jit(self._stack_rows)
+        self.n_assemble_traces = 0
+        self.n_rows_direct = 0
+        self.n_rows_staged = 0
 
     @property
     def cost(self):
@@ -268,25 +288,70 @@ class ServingPipeline:
         with."""
         return self._plan.stages
 
+    def _stack_rows(self, rows: Dict[str, List[np.ndarray]]
+                    ) -> Dict[str, jax.Array]:
+        """``assemble``'s body: every call passes ``batch_size`` host rows
+        per direct input, so it traces and compiles once per pipeline."""
+        self.n_assemble_traces += 1         # runs only while tracing
+        return {k: jnp.stack(v) for k, v in rows.items()}
+
+    def _direct_rows(self, reqs: List[Dict[str, np.ndarray]]
+                     ) -> Dict[str, List[np.ndarray]]:
+        """Each direct input's ``batch_size`` rows as submitted (the fp32
+        cast of `stage_batch`, which copies nothing for fp32 rows), a
+        ragged tail padded by repeating the last row. The runtime then
+        transfers that row once per padding slot; in exchange every call
+        passes host rows only, the one signature `register`'s warm-up
+        compiles (a padding device array would be a second one, met
+        first inside a serving window)."""
+        rows = {}
+        pad = self.batch_size - len(reqs)
+        for k, shape in self.staging.direct_shapes.items():
+            rows[k] = [np.asarray(r[k], np.float32) for r in reqs]
+            for row in rows[k]:
+                if row.shape != shape:
+                    raise ValueError(f"input {k!r}: row shape {row.shape} "
+                                     f"!= planned {shape}")
+            rows[k] += rows[k][-1:] * pad
+        return rows
+
     def _stage(self, reqs: List[Dict[str, np.ndarray]]
-               ) -> Tuple[Dict[str, jax.Array], Optional[int]]:
-        """Stage one batch into an arena slot (in-place reuse), falling
-        back to a fresh `stage_batch` allocation when the pool is dry.
-        Returns (device batch, owned slot or None)."""
+               ) -> Tuple[Dict[str, jax.Array], Optional[int],
+                          Tuple[np.ndarray, ...]]:
+        """Stage one batch: host-staged inputs into an arena slot
+        (in-place reuse) and one `jax.device_put`; direct inputs by
+        passing their host rows to the jitted ``assemble``, whose
+        compiled call hands each to the runtime and stacks them on the
+        device. Falls back to a fresh `stage_batch` allocation of every
+        input when the pool is dry. Returns (device batch, owned slot or
+        None, the host rows the runtime was handed)."""
         if not reqs:
             raise ValueError("stage_batch needs at least one request")
         if len(reqs) > self.batch_size:
             raise ValueError(
                 f"{len(reqs)} requests > batch size {self.batch_size}")
+        n = len(reqs)
         slot = self.arena.acquire()
         if slot is None:
             self.arena.n_fallback += 1
             with spans.span("serve.stage"):
-                return stage_batch(reqs, self.batch_size), None
-        with spans.span("serve.stage"):
-            host = self.arena.stage(slot, reqs)
-        with spans.span("serve.transfer"):
-            return jax.device_put(host), slot
+                staged = stage_batch(reqs, self.batch_size)
+            self.n_rows_staged += n * len(staged)
+            return staged, None, ()
+        try:
+            with spans.span("serve.stage"):
+                host = self.arena.stage(slot, reqs)
+                rows = self._direct_rows(reqs)
+            with spans.span("serve.transfer"):
+                staged = jax.device_put(host)
+                if rows:
+                    staged.update(self._assemble(rows))
+        except BaseException:
+            self.arena.release(slot)
+            raise
+        self.n_rows_staged += n * len(host)
+        self.n_rows_direct += n * len(rows)
+        return staged, slot, tuple(r for v in rows.values() for r in v[:n])
 
     def _dispatch(self, staged: Dict[str, jax.Array], rng: jax.Array
                   ) -> Tuple[Dict[str, jax.Array], jax.Array]:
@@ -297,7 +362,8 @@ class ServingPipeline:
             return self._plan(staged, rngs[1:]), rngs[0]
 
     def _issue(self, staged: Dict[str, jax.Array], slot: Optional[int],
-               n_real: int, stage_time: float, rng: jax.Array
+               rows: Tuple[np.ndarray, ...], n_real: int,
+               stage_time: float, rng: jax.Array
                ) -> Tuple[DispatchTicket, jax.Array]:
         try:
             out, carry = self._dispatch(staged, rng)
@@ -306,7 +372,7 @@ class ServingPipeline:
                 self.arena.release(slot)
             raise
         ticket = DispatchTicket(self, out, n_real, slot, stage_time,
-                                time.perf_counter())
+                                time.perf_counter(), rows)
         self._inflight.append(ticket)
         return ticket, carry
 
@@ -333,9 +399,9 @@ class ServingPipeline:
         if rng is None:
             rng = jax.random.PRNGKey(0)
         t0 = time.perf_counter()
-        staged, slot = self._stage(reqs)
+        staged, slot, rows = self._stage(reqs)
         t1 = time.perf_counter()
-        ticket, _ = self._issue(staged, slot, len(reqs), t1 - t0, rng)
+        ticket, _ = self._issue(staged, slot, rows, len(reqs), t1 - t0, rng)
         return ticket
 
     def execute_batch(self, reqs: List[Dict[str, np.ndarray]],
@@ -393,9 +459,10 @@ class ServingPipeline:
                 while tickets and self.arena.n_free == 0:
                     _retire_next()
             t0 = time.perf_counter()
-            staged, slot = self._stage(chunk)
+            staged, slot, rows = self._stage(chunk)
             stage_t = time.perf_counter() - t0
-            ticket, rng = self._issue(staged, slot, len(chunk), stage_t, rng)
+            ticket, rng = self._issue(staged, slot, rows, len(chunk),
+                                      stage_t, rng)
             tickets.append(ticket)
             if not pipeline:
                 _retire_next()

@@ -216,6 +216,8 @@ class ModelTelemetry:
     backend_counts: Dict[str, int] = dataclasses.field(default_factory=dict)
     # -- degraded-mode accounting (DESIGN.md §13) ----------------------------
     n_staging_fallbacks: int = 0        # host arena pool misses (fresh alloc)
+    n_rows_direct: int = 0              # (input, row)s handed to the runtime
+    n_rows_staged: int = 0              # (input, row)s copied on the host
     n_failed_dispatches: int = 0        # dispatches whose retirement raised
 
     @property
@@ -1106,10 +1108,12 @@ class ContinuousBatchingScheduler:
                 tel.n_failed_dispatches = sum(
                     1 for d in self.dispatches
                     if d.model == name and d.failed)
-                tel.n_staging_fallbacks = sum(
-                    p.arena.n_fallback
-                    for rungs in svc.pipelines.values()
-                    for p in rungs.values())
+                pipes = [p for rungs in svc.pipelines.values()
+                         for p in rungs.values()]
+                tel.n_staging_fallbacks = sum(p.arena.n_fallback
+                                              for p in pipes)
+                tel.n_rows_direct = sum(p.n_rows_direct for p in pipes)
+                tel.n_rows_staged = sum(p.n_rows_staged for p in pipes)
                 tel.n_completed = len(comps)
                 tel.n_kept = sum(c.kept for c in comps)
                 tel.deadline_misses = sum(c.missed_deadline for c in comps)
@@ -1167,6 +1171,10 @@ class ContinuousBatchingScheduler:
                 f"fill={tel.mean_batch_fill:.0%} over {tel.n_dispatches} "
                 f"dispatches  kept={tel.n_kept} "
                 f"(downlink -{tel.downlink_reduction:.0%})")
+            lines.append(
+                f"    staging: rows direct={tel.n_rows_direct}  "
+                f"staged={tel.n_rows_staged}  "
+                f"fallbacks={tel.n_staging_fallbacks}")
             if tel.energy_j > 0:
                 mix = " ".join(f"{b}:{c}" for b, c in
                                sorted(tel.backend_counts.items()))

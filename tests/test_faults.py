@@ -163,6 +163,27 @@ def test_staging_flip_is_transient(engines):
         np.testing.assert_array_equal(again.outputs[k], ref.outputs[k])
 
 
+@pytest.mark.parametrize("name,staged", [("vae_encoder", None),
+                                         ("cnet_plus_scalar",
+                                          "background_flux")])
+def test_staging_flip_targets_host_staged_buffers_only(name, staged):
+    """Rows handed to the runtime directly have no slot buffer: the flip
+    lands in a host-staged one (the CNet scalar), and a slot that holds
+    none (the VAE's) raises instead of picking from nothing."""
+    from repro.core.pipeline import ServingPipeline
+    m = SPACE_MODELS[name]
+    e = Engine(m.build_graph(), m.init_params(jax.random.PRNGKey(0)))
+    pipe = ServingPipeline(e, backend="flex", batch_size=4)
+    inj = faults.SEUInjector(seed=0)
+    if staged is None:
+        with pytest.raises(ValueError, match="holds no host buffer"):
+            inj.flip_staging(pipe.arena, slot=0)
+        assert inj.n_flips == 0
+    else:
+        buf, _, _ = inj.flip_staging(pipe.arena, slot=0)
+        assert buf == staged
+
+
 # ---------------------------------------------------------------------------
 # canaries
 # ---------------------------------------------------------------------------

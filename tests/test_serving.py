@@ -240,6 +240,118 @@ def test_arena_reuse_never_retraces(backend, engines):
 
 
 # ---------------------------------------------------------------------------
+# direct rows: large inputs go to the runtime as submitted (DESIGN.md §12)
+# ---------------------------------------------------------------------------
+
+# nets whose image rows exceed memory.DIRECT_ROW_BYTES
+LARGE_MODELS = ("cnet_plus_scalar", "vae_encoder")
+
+
+@pytest.fixture(scope="module")
+def large_engines():
+    out = {}
+    for name in LARGE_MODELS:
+        m = SPACE_MODELS[name]
+        e = Engine(m.build_graph(), m.init_params(jax.random.PRNGKey(0)))
+        e.calibrate([m.synthetic_input(jax.random.PRNGKey(0))])
+        out[name] = (m, e)
+    return out
+
+
+def _plan_on_stage_batch(pipe, chunk, rng):
+    """The compiled plan fed `stage_batch`'s host-stacked batch, with the
+    rng split `_dispatch` makes: the reference of the direct-row path."""
+    rngs = jax.random.split(rng, pipe.batch_size + 1)
+    out = pipe._plan(stage_batch(chunk, pipe.batch_size), rngs[1:])
+    return {k: np.asarray(v)[:len(chunk)] for k, v in out.items()}
+
+
+@pytest.mark.parametrize("backend", ["flex", "accel"])
+@pytest.mark.parametrize("name", LARGE_MODELS)
+def test_direct_rows_bit_identical_to_stage_batch(name, backend,
+                                                  large_engines):
+    """A full batch, then shrinking ragged tails (4, 2, 1 rows) through
+    ONE reused slot: rows handed to the runtime and stacked on the device
+    give outputs bit-identical to the plan run on `stage_batch`."""
+    m, e = large_engines[name]
+    B = 4
+    reqs = _requests(m, 7)
+    pipe = ServingPipeline(e, backend=backend, batch_size=B,
+                           staging_buffers=1)
+    assert pipe.staging.direct_shapes == {"image": m.build_graph()
+                                          .graph_inputs["image"]}
+    for lo, hi in ((0, 4), (4, 6), (6, 7)):
+        chunk = reqs[lo:hi]
+        rng = jax.random.PRNGKey(lo)
+        got = pipe.execute_batch(chunk, rng=rng)
+        ref = _plan_on_stage_batch(pipe, chunk, rng)
+        assert set(got.outputs) == set(ref)
+        for k in ref:
+            np.testing.assert_array_equal(
+                got.outputs[k], ref[k],
+                err_msg=f"{name}/{backend}/{k} chunk [{lo}:{hi}]")
+    assert pipe.arena.n_staged == 3             # all via the one slot
+    assert pipe.arena.n_fallback == 0
+    assert pipe.arena.n_free == 1               # every slot returned
+
+
+@pytest.mark.parametrize("name", LARGE_MODELS)
+def test_assemble_compiles_once_and_plan_never_retraces(name,
+                                                        large_engines):
+    """The on-device stack compiles once per pipeline (a ragged tail is
+    padded to the rung by repeating its last row, so every call has the
+    rung's shape), and the plan never re-traces across direct, ragged
+    and fallback batches."""
+    m, e = large_engines[name]
+    reqs = _requests(m, 11)
+    pipe = ServingPipeline(e, backend="flex", batch_size=4,
+                           staging_buffers=1)
+    before = e.planned("flex").n_traces
+    tickets = [pipe.execute_batch_async(reqs[:4]),
+               pipe.execute_batch_async(reqs[4:8])]   # 2nd one falls back
+    assert tickets[0].rows and not tickets[1].rows
+    for t in tickets:
+        t.retire()
+    assert not tickets[0].rows                  # released at retirement
+    for hi in (10, 9):
+        pipe.execute_batch(reqs[8:hi])          # ragged, 2 then 1 rows
+    assert pipe.n_assemble_traces == 1
+    assert e.planned("flex").n_traces == before
+    assert pipe.arena.n_fallback == 1
+
+
+def test_direct_row_shape_mismatch_raises_and_frees_slot(large_engines):
+    m, e = large_engines["vae_encoder"]
+    pipe = ServingPipeline(e, backend="flex", batch_size=4,
+                           staging_buffers=1)
+    bad = {"image": np.zeros((64, 256, 3), np.float32)}
+    with pytest.raises(ValueError, match="row shape"):
+        pipe.execute_batch([bad])
+    assert pipe.arena.n_free == 1 and pipe.n_assemble_traces == 0
+
+
+def test_row_counters_direct_vs_staged(engines, large_engines):
+    """CNet frames go direct and its scalar is staged; every input of
+    multi_esperta is staged. The scheduler sums the counters."""
+    sched = ContinuousBatchingScheduler(clock="modeled", pipeline=True)
+    trace = []
+    for name, (m, e) in (("cnet_plus_scalar",
+                          large_engines["cnet_plus_scalar"]),
+                         ("multi_esperta", engines["multi_esperta"])):
+        sched.register(name, e, backend="flex", ladder=(1, 4))
+        trace += [(0.001 * i, name, r)
+                  for i, r in enumerate(_requests(m, 6))]
+    sched.serve_trace(sorted(trace, key=lambda x: x[0]))
+    tel = sched.telemetry()
+    cnet, esperta = tel["cnet_plus_scalar"], tel["multi_esperta"]
+    assert (cnet.n_rows_direct, cnet.n_rows_staged) == (6, 6)
+    n_keys = len(SPACE_MODELS["multi_esperta"].build_graph().graph_inputs)
+    assert (esperta.n_rows_direct, esperta.n_rows_staged) == (0, 6 * n_keys)
+    assert cnet.n_staging_fallbacks == esperta.n_staging_fallbacks == 0
+    assert "rows direct=6  staged=6" in sched.summary()
+
+
+# ---------------------------------------------------------------------------
 # scheduler behavior
 # ---------------------------------------------------------------------------
 
