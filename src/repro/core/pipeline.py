@@ -26,7 +26,9 @@ Synchronization contract (DESIGN.md §12): no path here ever calls
 ``np.asarray`` per output, which blocks on exactly that batch — when its
 :class:`DispatchTicket` retires: immediately in :meth:`execute_batch`,
 lazily (slot-pool exhaustion, stream end, or an explicit :meth:`sync`
-telemetry barrier) in the pipelined paths.
+telemetry barrier) in the pipelined paths. The scheduler's wall-clock
+dispatcher also retires a ticket as soon as :meth:`DispatchTicket.ready`
+says its outputs are done, whenever it has nothing to dispatch.
 """
 from __future__ import annotations
 
@@ -203,6 +205,16 @@ class DispatchTicket:
     @property
     def retired(self) -> bool:
         return self._result is not None
+
+    def ready(self) -> bool:
+        """Whether ``retire()`` would return without waiting on the
+        device: every output reports ``is_ready()`` (a host value, which
+        has no such method, is ready), or the ticket already retired or
+        failed. Forces and copies nothing."""
+        if self._result is not None or self.outputs is None:
+            return True
+        return all(v.is_ready() for v in self.outputs.values()
+                   if hasattr(v, "is_ready"))
 
     def _release(self) -> None:
         self.rows = ()

@@ -43,9 +43,12 @@ the keep predicate*.
 ``pipeline=True`` (DESIGN.md §12) switches dispatch to the ASYNC ticket
 path: ``execute_batch_async`` returns without forcing the outputs, up to
 ``staging_buffers`` dispatches stay in flight (each owning a reusable
-host staging slot), and tickets retire lazily — at slot-pool pressure,
-at every telemetry boundary, and at stream end. EWMA service times are
-observed at ticket retirement. Dispatch DECISIONS are unchanged, and
+host staging slot). Tickets retire oldest-first: on readiness, as soon
+as their outputs are done, whenever the wall-clock dispatcher has
+nothing to dispatch; otherwise at slot-pool pressure, at every
+telemetry boundary, and at stream end. Each dispatch record names the
+cause (``retired_by``). EWMA service times are observed at ticket
+retirement. Dispatch DECISIONS are unchanged, and
 under ``clock="modeled"`` pipelined serving is dispatch-for-dispatch and
 bit-exact identical to ``pipeline=False``; the overlap a pipelined
 deployment would realize is priced by a deterministic per-resource
@@ -155,6 +158,11 @@ class DispatchRecord:
     energy_j: float = 0.0               # modeled energy of the dispatch
     power_w: float = 0.0                # modeled busy power while it ran
     failed: bool = False                # retirement raised; batch requeued
+    # what retired the batch (pipelined mode): 'ready' (the idle
+    # dispatcher found its outputs done), 'slot' (a dispatch needed its
+    # staging slot) or 'sync' (a barrier or stream end). How the host
+    # learned of the result, not a dispatch decision: left out of ==
+    retired_by: str = dataclasses.field(default="", compare=False)
 
     @property
     def fill(self) -> float:
@@ -181,6 +189,7 @@ class _Inflight:
     draw: Optional[Draw]
     rec_idx: int                        # index into scheduler.dispatches
     t0: float                           # wall perf_counter at dispatch
+    retired_by: str = ""                # set by whoever pops it to retire
 
 
 @dataclasses.dataclass(frozen=True)
@@ -219,6 +228,9 @@ class ModelTelemetry:
     n_rows_direct: int = 0              # (input, row)s handed to the runtime
     n_rows_staged: int = 0              # (input, row)s copied on the host
     n_failed_dispatches: int = 0        # dispatches whose retirement raised
+    n_retired_ready: int = 0            # retired on readiness, idle loop
+    n_retired_slot: int = 0             # retired for a dispatch's slot
+    n_retired_sync: int = 0             # retired at a barrier / stream end
 
     @property
     def downlink_reduction(self) -> float:
@@ -711,7 +723,7 @@ class ContinuousBatchingScheduler:
         # staging_buffers dispatches in flight — so every pipeline's
         # slot pool can double-buffer instead of falling back to fresh
         # allocations
-        self._drain_inflight(self.staging_buffers - 1)
+        self._drain_inflight(self.staging_buffers - 1, "slot")
         t0 = time.perf_counter()
         try:
             ticket = svc.pipelines[backend][rung].execute_batch_async(
@@ -754,8 +766,9 @@ class ContinuousBatchingScheduler:
     def _retire(self, inf: _Inflight) -> None:
         """Finish one in-flight dispatch: force its outputs (releasing
         the staging slot), observe the EWMA service time from ticket
-        retirement, and emit its completions (FIFO retirement keeps
-        completion order identical to the synchronous path)."""
+        retirement, record ``inf.retired_by`` on the dispatch record, and
+        emit its completions (FIFO retirement keeps completion order
+        identical to the synchronous path)."""
         with spans.span("serve.retire", inf.reqs[0].rid):
             try:
                 result = inf.ticket.retire()
@@ -778,12 +791,13 @@ class ContinuousBatchingScheduler:
                        else measured)
             with self._lock:
                 inf.svc.observe_service(inf.backend, inf.rung, service)
-                if self.clock != "modeled":
-                    # telemetry should report the true dispatch->retirement
-                    # service; the virtual clock already advanced by the
-                    # non-blocking dispatch time at dispatch
-                    self.dispatches[inf.rec_idx] = dataclasses.replace(
-                        self.dispatches[inf.rec_idx], service_time=service)
+                # measured clock: telemetry should report the true
+                # dispatch->retirement service; the virtual clock already
+                # advanced by the non-blocking dispatch time at dispatch.
+                # (Modeled clock: the record already holds this latency.)
+                self.dispatches[inf.rec_idx] = dataclasses.replace(
+                    self.dispatches[inf.rec_idx], service_time=service,
+                    retired_by=inf.retired_by)
                 finished = inf.started + service
                 for i, req in enumerate(inf.reqs):
                     self.completions.append(Completion(
@@ -792,19 +806,34 @@ class ContinuousBatchingScheduler:
                         result.keep[i], req.arrival, finished, inf.rung,
                         inf.n_real, req.deadline))
 
-    def _drain_inflight(self, keep: int = 0) -> None:
-        """Retire oldest-first until at most ``keep`` remain in flight."""
+    def _drain_inflight(self, keep: int, cause: str) -> None:
+        """Retire oldest-first until at most ``keep`` remain in flight,
+        waiting on the device where a batch is not done yet."""
         while True:
             with self._lock:
                 if len(self._inflight) <= keep:
                     return
                 inf = self._inflight.popleft()
+            inf.retired_by = cause
+            self._retire(inf)
+
+    def _retire_ready(self) -> None:
+        """Retire, oldest-first, the in-flight tickets whose outputs are
+        already done, stopping at the first that is not: it never blocks,
+        and completions stay in dispatch order. Goes through
+        ``self._retire`` like every other retirement."""
+        while True:
+            with self._lock:
+                if not self._inflight or not self._inflight[0].ticket.ready():
+                    return
+                inf = self._inflight.popleft()
+            inf.retired_by = "ready"
             self._retire(inf)
 
     def sync(self) -> None:
         """Retire every in-flight ticket — the telemetry/stream barrier.
         A no-op in synchronous mode (nothing is ever in flight)."""
-        self._drain_inflight(0)
+        self._drain_inflight(0, "sync")
 
     def _earliest_admit(self, svc: _ModelService, rung: int, now: float
                         ) -> Optional[float]:
@@ -1062,7 +1091,13 @@ class ContinuousBatchingScheduler:
             while not self._stop.is_set():
                 try:
                     rec = self.step(time.monotonic())
-                except BaseException as ex:     # batch re-queued by step()
+                    if rec is None:
+                        # nothing to dispatch: hand finished batches to
+                        # the host now, not when a later dispatch needs
+                        # their slot
+                        self._retire_ready()
+                except BaseException as ex:
+                    # the batch was re-queued; stop() re-raises this
                     self._thread_error = ex
                     return
                 if rec is None:
@@ -1108,6 +1143,9 @@ class ContinuousBatchingScheduler:
                 tel.n_failed_dispatches = sum(
                     1 for d in self.dispatches
                     if d.model == name and d.failed)
+                tel.n_retired_ready, tel.n_retired_slot, tel.n_retired_sync = (
+                    sum(d.retired_by == cause for d in disps)
+                    for cause in ("ready", "slot", "sync"))
                 pipes = [p for rungs in svc.pipelines.values()
                          for p in rungs.values()]
                 tel.n_staging_fallbacks = sum(p.arena.n_fallback
@@ -1175,6 +1213,9 @@ class ContinuousBatchingScheduler:
                 f"    staging: rows direct={tel.n_rows_direct}  "
                 f"staged={tel.n_rows_staged}  "
                 f"fallbacks={tel.n_staging_fallbacks}")
+            lines.append(
+                f"    retire: ready={tel.n_retired_ready}  "
+                f"slot={tel.n_retired_slot}  sync={tel.n_retired_sync}")
             if tel.energy_j > 0:
                 mix = " ".join(f"{b}:{c}" for b, c in
                                sorted(tel.backend_counts.items()))

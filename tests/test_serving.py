@@ -16,6 +16,10 @@ stream, and the continuous-batching scheduler.
 * Scheduler: co-serves two models round-robin, drops/duplicates nothing,
   dispatches only ladder rungs, precompiles the ladder (serving never
   re-traces), and the async wall-clock mode completes every request.
+* Pipelined wall-clock mode retires a batch as soon as its outputs are
+  done when the dispatcher idles, oldest first, recovers from a failure
+  found there as from one found at dispatch, and names each record's
+  cause of retirement.
 * Power envelope: tightening the budget mid-trace degrades dispatch
   (smaller rungs, cpu/flex fallback, recorded deferrals) without ever
   dropping or duplicating a request and with a clean envelope audit; a
@@ -30,7 +34,8 @@ import pytest
 
 from repro.core.energy import PowerEnvelope
 from repro.core.engine import Engine
-from repro.core.pipeline import ServeStats, ServingPipeline, stage_batch
+from repro.core.pipeline import (DispatchTicket, ServeStats, ServingPipeline,
+                                 stage_batch)
 from repro.core.scheduler import (ContinuousBatchingScheduler,
                                   bursty_arrivals, poisson_arrivals)
 from repro.models import SPACE_MODELS, synthetic_requests
@@ -607,6 +612,136 @@ def test_scheduler_async_mode_completes_everything(engines):
         sched.stop(drain=True)
     got = sorted(c.rid for c in sched.completions)
     assert got == sorted(rids)
+
+
+# ---------------------------------------------------------------------------
+# retirement on readiness (pipelined wall-clock mode)
+# ---------------------------------------------------------------------------
+
+
+def _pipelined_esperta(engines, keep_predicate=None, staging_buffers=2):
+    m, e = engines["multi_esperta"]
+    reqs = _requests(m, 8)
+    sched = ContinuousBatchingScheduler(pipeline=True,
+                                        staging_buffers=staging_buffers)
+    sched.register("multi_esperta", e, backend="flex", ladder=(1, 4),
+                   keep_predicate=keep_predicate, warmup_sample=reqs[0])
+    return sched, reqs
+
+
+def _wait_for(cond, timeout_s=10.0):
+    deadline = time.time() + timeout_s
+    while not cond() and time.time() < deadline:
+        time.sleep(0.001)
+    return cond()
+
+
+def test_ticket_ready_checks_without_retiring(engines):
+    class Pending:
+        def is_ready(self):
+            return False
+
+    m, e = engines["multi_esperta"]
+    pipe = ServingPipeline(e, backend="flex", batch_size=4)
+    ticket = pipe.execute_batch_async(_requests(m, 4))
+    assert _wait_for(ticket.ready)
+    assert not ticket.retired and ticket.slot is not None
+    ticket.retire()
+    assert ticket.ready()
+    host = {"y": np.zeros(4, np.float32)}      # no is_ready: on the host
+    assert DispatchTicket(pipe, host, 4, None, 0.0, 0.0).ready()
+    assert not DispatchTicket(pipe, dict(host, z=Pending()), 4, None,
+                              0.0, 0.0).ready()
+
+
+def test_lone_batch_retires_while_the_dispatcher_runs(engines):
+    """A batch with no dispatch after it reaches the host once its
+    outputs are done, from the dispatcher's idle loop: not at the next
+    dispatch, and not only at stop()."""
+    sched, reqs = _pipelined_esperta(engines)
+    sched.start(poll_s=0.0005)
+    try:
+        rids = [sched.submit("multi_esperta", r) for r in reqs[:4]]
+        done = _wait_for(lambda: len(sched.completions) == 4)
+    finally:
+        sched.stop(drain=True)
+    assert done
+    assert sorted(c.rid for c in sched.completions) == sorted(rids)
+    assert [d.retired_by for d in sched.dispatches] == ["ready"]
+
+
+def test_ready_retirement_keeps_dispatch_order(engines):
+    """A ready ticket behind one whose outputs are not done waits: the
+    idle loop retires nothing past the head, then both in order."""
+    sched, reqs = _pipelined_esperta(engines, staging_buffers=3)
+    for batch in (reqs[:4], reqs[4:]):
+        for r in batch:
+            sched.submit("multi_esperta", r)
+        assert sched.step(time.monotonic()) is not None
+    head, tail = sched._inflight
+    assert _wait_for(tail.ticket.ready)
+    gate = {"open": False}
+    head.ticket.ready = lambda: gate["open"]
+    sched._retire_ready()
+    assert len(sched._inflight) == 2 and not sched.completions
+    gate["open"] = True
+    sched._retire_ready()
+    assert not sched._inflight
+    assert [c.rid for c in sched.completions] == list(range(8))
+    assert [d.retired_by for d in sched.dispatches] == ["ready", "ready"]
+
+
+def test_idle_retirement_error_requeues_and_reraises(engines):
+    """An async failure found by the idle loop recovers as one found at
+    dispatch does: the batch back at the queue head in its order, the
+    record failed, the error re-raised by stop()."""
+    armed = {"on": False}
+
+    def exploding_keep(out):
+        if armed["on"]:
+            raise RuntimeError("keep predicate exploded")
+        return True
+
+    sched, reqs = _pipelined_esperta(engines, keep_predicate=exploding_keep)
+    armed["on"] = True
+    sched.start(poll_s=0.0005)
+    rids = [sched.submit("multi_esperta", r) for r in reqs[:4]]
+    # one dispatch and no other: only the idle loop can have retired it
+    assert _wait_for(lambda: sched._thread_error is not None)
+    with pytest.raises(RuntimeError, match="exploded"):
+        sched.stop(drain=False)
+    svc = sched._svcs["multi_esperta"]
+    assert [r.rid for r in svc.queue] == rids
+    assert len(sched.dispatches) == 1 and sched.dispatches[0].failed
+    assert not sched.completions
+
+
+@pytest.mark.parametrize("drive", ["wall_clock", "trace"])
+def test_retirement_causes_count_every_dispatch(drive, engines):
+    sched, _ = _pipelined_esperta(engines)
+    m, _ = engines["multi_esperta"]
+    reqs = _requests(m, 23, seed=5)
+    if drive == "wall_clock":
+        sched.start(poll_s=0.0005)
+        try:
+            for r in reqs:
+                sched.submit("multi_esperta", r)
+        finally:
+            sched.stop(drain=True)
+    else:
+        sched.serve_trace([(0.001 * i, "multi_esperta", r)
+                           for i, r in enumerate(reqs)])
+    tel = sched.telemetry()["multi_esperta"]
+    assert tel.n_completed == len(reqs)
+    assert (tel.n_retired_ready + tel.n_retired_slot + tel.n_retired_sync
+            == tel.n_dispatches == len(sched.dispatches))
+    assert all(d.retired_by in ("ready", "slot", "sync")
+               for d in sched.dispatches)
+    if drive == "trace":                  # no idle loop off the wall clock
+        assert tel.n_retired_ready == 0 and tel.n_retired_sync >= 1
+    assert (f"retire: ready={tel.n_retired_ready}  "
+            f"slot={tel.n_retired_slot}  sync={tel.n_retired_sync}"
+            in sched.summary())
 
 
 # ---------------------------------------------------------------------------
